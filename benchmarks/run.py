@@ -16,6 +16,7 @@ import sys
 import time
 
 from benchmarks import common
+from repro.utils.compile_cache import enable_compile_cache
 
 SUITES = [
     ("kernels", "benchmarks.bench_kernels"),          # kernel micro
@@ -35,6 +36,7 @@ JSON_SUITES = {"aggregation", "kernels", "crosstest", "population",
 
 
 def main() -> int:
+    enable_compile_cache()
     want = set(sys.argv[1:])
     failed = []
     print("name,us_per_call,derived")

@@ -40,16 +40,6 @@ from repro.kernels.weighted_aggregate import aggregate_pytree
 from repro.utils.pytree import tree_add_vector
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (experimental pre-0.5)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 def _flatten_updates(stacked, global_params) -> jnp.ndarray:
     """[N, D] float32 matrix of flattened client updates."""
     def flat(stack, g):
@@ -415,10 +405,10 @@ def make_pod_round(model, fed: FedConfig, train_cfg: TrainConfig, mesh,
 
     if program.use_compression:
         @functools.partial(
-            _shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(), P(), P(), P(axis), P(axis), P(axis), P(axis),
                       P(), P()),
-            out_specs=(P(), P(), P(), P()))
+            out_specs=(P(), P(), P(), P()), check_vma=False)
         def round_fn(global_params, scores, comp, bx, by, tx, ty, key,
                      round_idx):
             bx, by = bx[0], by[0]
@@ -437,9 +427,9 @@ def make_pod_round(model, fed: FedConfig, train_cfg: TrainConfig, mesh,
         return round_fn
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis), P(axis), P(axis), P(), P()),
-        out_specs=(P(), P(), P()))
+        out_specs=(P(), P(), P()), check_vma=False)
     def round_fn(global_params, scores, bx, by, tx, ty, key, round_idx):
         # shard_map gives per-client leading axes of size 1 — drop them
         bx, by = bx[0], by[0]

@@ -203,6 +203,15 @@ class FederatedTrainer:
     def run_round(self, state: RoundState, data: FederatedDataset):
         return self._round_fn(state, data)
 
+    def compile_driver(self, state: RoundState, data: FederatedDataset):
+        """Compile, ahead of time, the program that ``run`` dispatches
+        for a full chunk (the scanned driver when ``rounds_per_call >
+        1``). JAX's in-memory cache then serves ``run``'s calls with the
+        same argument types, so this splits compilation from the first
+        round and exposes the compiled HLO and its memory analysis."""
+        fn = self._scan_fn or self._round_fn
+        return fn.lower(state, data).compile()
+
     def global_accuracy(self, state: RoundState, data: FederatedDataset,
                         max_samples: int = 2048) -> float:
         return float(self._global_eval(state.global_params,

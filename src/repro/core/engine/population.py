@@ -39,7 +39,9 @@ So the round runs on a gathered ``[C, ...]`` model stack
 Sharding: with a ``mesh``, the cohort axis is annotated with
 ``with_sharding_constraint`` so GSPMD splits the [C] stack, batches and
 eval tiles across a ``clients`` mesh axis — the multi-device smoke in
-CI. Cross-device reductions are not bitwise-stable, so the parity
+CI. The Pallas aggregation kernels cannot be partitioned by GSPMD, so
+there each device reduces its slice of the cohort and one psum adds the
+partial sums. Cross-device reductions are not bitwise-stable, so the parity
 matrix runs unsharded; the sharded path is gated on suppression
 (``--assert-malicious-below``), not bit-equality. DESIGN.md §11.
 """
@@ -221,13 +223,35 @@ class PopulationBackend(ExchangeBackend):
             return out.at[models.idx].set(accs, mode="drop")
         return thunk
 
+    def _cohort_sum(self, fn, *cohort_args):
+        """``fn`` reduces cohort-stacked ``[C, ...]`` args to one sum.
+
+        Pallas kernels do not partition automatically, so on a mesh each
+        device runs ``fn`` on its slice of the cohort and one psum adds
+        the partial sums (in f32).
+        """
+        if self.mesh is None:
+            return fn(*cohort_args)
+
+        def part(*args):
+            return jax.tree_util.tree_map(
+                lambda x: jax.lax.psum(x.astype(jnp.float32),
+                                       self.axis).astype(x.dtype),
+                fn(*args))
+
+        return jax.shard_map(part, mesh=self.mesh,
+                             in_specs=(P(self.axis),) * len(cohort_args),
+                             out_specs=P(), check_vma=False)(*cohort_args)
+
     def weighted_sum(self, models, weights, global_params, impl):
         # weights is the renormalised [N] simplex with exact zeros
         # outside the (effective) cohort, so summing over the gathered
         # stack is bitwise the full-population sum; sentinel slots are
         # zeroed by `valid` (their gathered weight is a real client's).
         w = weights[self._safe_idx(models)] * models.valid
-        return aggregate_pytree(models.stack, w, impl=impl)
+        return self._cohort_sum(
+            lambda stack, w: aggregate_pytree(stack, w, impl=impl),
+            models.stack, w)
 
     def compress_exchange(self, compressor, models, global_params,
                           comp_state, part_mask):
@@ -261,7 +285,9 @@ class PopulationBackend(ExchangeBackend):
         # simplex gathered to the cohort rows loses only exact-zero
         # summands
         w = weights[self._safe_idx(models)] * models.valid
-        return compressor.aggregate(payloads, decoded, w, impl)
+        return self._cohort_sum(
+            lambda p, d, w: compressor.aggregate(p, d, w, impl),
+            payloads, decoded, w)
 
 
 @dataclasses.dataclass
@@ -329,6 +355,20 @@ class PopulationTrainer(FederatedTrainer):
         return PopulationBackend(self.fed.num_users, self.capacity, impl,
                                  block=self.crosstest_block,
                                  mesh=self.mesh)
+
+    def _on_mesh(self, state: RoundState) -> RoundState:
+        # a sharded round returns its state replicated over the mesh;
+        # a state that starts there too keeps the compiled round's input
+        # types fixed, so the second round does not retrace
+        if self.mesh is None:
+            return state
+        return jax.device_put(state, NamedSharding(self.mesh, P()))
+
+    def init(self, key) -> RoundState:
+        return self._on_mesh(super().init(key))
+
+    def load_state(self, state_dict: dict) -> RoundState:
+        return self._on_mesh(super().load_state(state_dict))
 
     def _round_body(self, state: RoundState, data):
         self.num_traces += 1

@@ -267,6 +267,7 @@ class RoundProgram:
                  batch_builder: Optional[Callable] = None,
                  aggregator=None):
         self.model = model
+        self.train_model = model.for_training()
         self.fed = fed
         self.train_cfg = train_cfg
         self.agg_impl = agg_impl
@@ -332,7 +333,7 @@ class RoundProgram:
             params, opt_state = carry
             xb, yb = xb_yb
             (loss, _), grads = jax.value_and_grad(
-                self.model.loss, has_aux=True)(params,
+                self.train_model.loss, has_aux=True)(params,
                                                self.batchify(xb, yb))
             params, opt_state = self.opt.update(grads, opt_state, params)
             return (params, opt_state), loss

@@ -31,7 +31,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
     k_start = ki * block_k
     lo = length - window if window is not None else 0
     run = jnp.logical_and(k_start < length, k_start + block_k > lo)
@@ -64,7 +64,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[:, 0].astype(lse_ref.dtype)
+        lse_ref[0, 0] = (m_ref[...] + jnp.log(l)).astype(lse_ref.dtype)
 
 
 @functools.partial(
@@ -78,7 +78,9 @@ def decode_attention_pallas(q, k, v, lengths, *,
     """q [B,Hq,D]; k/v [B,T,Hkv,D]; lengths [B] -> (out [B,Hq,D], lse [B,Hq]).
 
     GQA grid: (B, Hkv, T // block_k); each step handles one kv head's whole
-    query-head group (rep = Hq // Hkv rows of q).
+    query-head group (rep = Hq // Hkv rows of q). ``lengths`` sits whole
+    in SMEM and the lse is emitted as ``[..., rep, 1]`` columns, so every
+    block's trailing dimensions are whole array dimensions.
     """
     B, Hq, D = q.shape
     _, T, Hkv, _ = k.shape
@@ -103,19 +105,18 @@ def decode_attention_pallas(q, k, v, lengths, *,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda b, g, ki: (b,),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, rep, D), lambda b, g, ki: (b, g, 0, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, g, ki: (b, g, ki, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, g, ki: (b, g, ki, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, rep, D), lambda b, g, ki: (b, g, 0, 0)),
-            pl.BlockSpec((1, 1, rep), lambda b, g, ki: (b, g, 0)),
+            pl.BlockSpec((1, 1, rep, 1), lambda b, g, ki: (b, g, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, rep, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, rep), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, rep, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((rep, D), jnp.float32),
@@ -123,5 +124,6 @@ def decode_attention_pallas(q, k, v, lengths, *,
             pltpu.VMEM((rep, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(lengths.astype(jnp.int32), qg, kh, vh)
     return out.reshape(B, Hq, D), lse.reshape(B, Hq)

@@ -136,5 +136,6 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qh, kh, vh)
     return out.transpose(0, 2, 1, 3)

@@ -102,6 +102,7 @@ def robust_combine_pallas(x: jnp.ndarray, mask: jnp.ndarray,
         out_specs=pl.BlockSpec((1, block_m), lambda mi: (0, mi)),
         out_shape=jax.ShapeDtypeStruct((1, M), x.dtype),
         interpret=interpret,
+        name="robust_combine",
     )(mask.astype(jnp.float32).reshape(C, 1),
       w_row.astype(jnp.float32).reshape(C, 1), x)
     return out[0]

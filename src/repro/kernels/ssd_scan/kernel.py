@@ -26,25 +26,37 @@ def _ssd_kernel(a_ref, d_ref, x_ref, dt_ref, b_ref, c_ref,
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    A = a_ref[0]                                   # scalar (negative)
-    Dh = d_ref[0]
+    h = pl.program_id(1)
+    A = a_ref[h]                                   # scalar (negative)
+    Dh = d_ref[h]
     xb = x_ref[0, 0].astype(jnp.float32)           # [Q, P]
     dtb = dt_ref[0, 0].astype(jnp.float32)         # [Q, 1]
     Bb = b_ref[0, 0].astype(jnp.float32)           # [Q, N]
     Cb = c_ref[0, 0].astype(jnp.float32)           # [Q, N]
 
     dA = dtb * A                                   # [Q, 1]
-    cum = jnp.cumsum(dA, axis=0)                   # [Q, 1] inclusive
     h0 = h_ref[...]                                # [P, N]
+
+    # Mosaic lowers neither cumsum nor narrow transposes, so the prefix
+    # sums and the row views of the [Q, 1] columns are masked
+    # reductions over the [Q, Q] tile (VPU work, small beside the
+    # matmuls below)
+    i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    lower = i >= j
+    dA_row = jnp.sum(jnp.where(i == j, dA, 0.0), axis=0, keepdims=True)
+    dt_row = jnp.sum(jnp.where(i == j, dtb, 0.0), axis=0, keepdims=True)
+    cum = jnp.sum(jnp.where(lower, dA_row, 0.0), axis=1,
+                  keepdims=True)                   # [Q, 1] inclusive
+    cum_row = jnp.sum(jnp.where(i <= j, dA, 0.0), axis=0,
+                      keepdims=True)               # [1, Q]
+    cum_last = jnp.sum(dA, axis=0, keepdims=True)  # [1, 1]
 
     # intra-chunk (the "duality" quadratic form)
     CB = jax.lax.dot_general(Cb, Bb, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # [Q, Q]
-    rel = cum - cum.T                               # cum_i - cum_j
-    i = jax.lax.broadcasted_iota(jnp.int32, CB.shape, 0)
-    j = jax.lax.broadcasted_iota(jnp.int32, CB.shape, 1)
-    rel = jnp.where(i >= j, rel, -1e30)             # mask before exp
-    L = jnp.exp(rel) * dtb.T                        # [Q, Q]
+    rel = jnp.where(lower, cum - cum_row, -1e30)    # mask before exp
+    L = jnp.exp(rel) * dt_row                       # [Q, Q]
     y = jax.lax.dot_general(CB * L, xb, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)   # [Q, P]
 
@@ -56,8 +68,8 @@ def _ssd_kernel(a_ref, d_ref, x_ref, dt_ref, b_ref, c_ref,
     y_ref[0, 0] = (y + Dh * xb).astype(y_ref.dtype)
 
     # state update: h <- exp(cum_Q) h + sum_j exp(cum_Q - cum_j) dt_j x_j B_j^T
-    w = jnp.exp(cum[-1:] - cum) * dtb                  # [Q, 1]
-    h_new = jnp.exp(cum[-1, 0]) * h0 + jax.lax.dot_general(
+    w = jnp.exp(cum_last - cum) * dtb                  # [Q, 1]
+    h_new = jnp.exp(cum_last) * h0 + jax.lax.dot_general(
         xb * w, Bb, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)            # [P, N]
     h_ref[...] = h_new
@@ -93,10 +105,8 @@ def ssd_scan_pallas(x, dt, A, B, C, D, *, chunk: int = 128,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ci: (h,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda b, h, ci: (h,),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),     # A [H], whole
+            pl.BlockSpec(memory_space=pltpu.SMEM),     # D [H], whole
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, ci: (b, h, ci, 0)),
             pl.BlockSpec((1, 1, chunk, 1), lambda b, h, ci: (b, h, ci, 0)),
             pl.BlockSpec((1, 1, chunk, N),
@@ -114,5 +124,6 @@ def ssd_scan_pallas(x, dt, A, B, C, D, *, chunk: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(A.astype(jnp.float32), D.astype(jnp.float32), xh, dth, Bg, Cg)
     return y.transpose(0, 2, 1, 3), state

@@ -40,5 +40,6 @@ def weighted_aggregate_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
         out_specs=pl.BlockSpec((1, block_m), lambda mi: (0, mi)),
         out_shape=jax.ShapeDtypeStruct((1, M), x.dtype),
         interpret=interpret,
+        name="weighted_aggregate",
     )(w.reshape(C, 1), x)
     return out[0]

@@ -78,11 +78,7 @@ def _lower_compile(cfg, shape, multi_pod, train_cfg=None,
     b_spec = batch_spec_tree(cfg, shape, mesh, batch)
 
     t0 = time.time()
-    # jax.set_mesh is 0.5+; the Mesh context manager covers older jax
-    set_mesh = getattr(jax, "set_mesh", None) or (lambda m: m)
-    with set_mesh(mesh), logical_rules(rules):
-        # NamedSharding works on every jax version; raw PartitionSpecs
-        # in in_shardings need 0.5+
+    with jax.set_mesh(mesh), logical_rules(rules):
         named = lambda spec: to_named(mesh, spec)   # noqa: E731
         if shape.kind == "train":
             step, _ = make_train_step(model, train_cfg)
@@ -113,8 +109,6 @@ def _lower_compile(cfg, shape, multi_pod, train_cfg=None,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # jax<=0.4: one dict per device
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     colls = parse_collectives(hlo)
     rec = {

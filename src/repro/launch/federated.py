@@ -29,6 +29,7 @@ import dataclasses
 import json
 import os
 import time
+from typing import Any, Iterator, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -42,7 +43,7 @@ _FED_CLI_DEFAULTS = dict(
     local_steps=6)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", type=int, default=8)
     ap.add_argument("--population", type=int, default=None,
@@ -147,40 +148,43 @@ def main():
                          "matrix is a lottery no scoring can separate)")
     ap.add_argument("--out", default="experiments/federated_pod")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
-    # the device count must be set before jax initialises
-    if "--xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.clients}")
 
+class PodRun(NamedTuple):
+    """What :func:`build_pod` makes from the parsed flags."""
+
+    model: Any          # repro.models.Model
+    fed: Any            # FedConfig
+    train: Any          # TrainConfig
+    data: Any           # FederatedDataset
+    round_fn: Any       # jitted shard_map round
+    comp: Any           # initial [N, D] error-feedback buffer or None
+
+
+def client_mesh(n: int):
+    """The 1-D ``clients`` mesh over the first ``n`` devices."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import Mesh
+    if len(jax.devices()) < n:
+        raise SystemExit(f"need {n} devices, have {len(jax.devices())}; "
+                         "set XLA_FLAGS before running")
+    return Mesh(np.asarray(jax.devices()[:n]), ("clients",))
+
+
+def build_pod(args: argparse.Namespace, mesh) -> PodRun:
+    """Model, configs, client data and the jitted pod round."""
+    import jax
 
     from repro.config import FedConfig, TrainConfig
     from repro.configs import get_config, scenario_for_pod
     from repro.core.engine import (
-        init_comp_state, make_allgather_round, make_distributed_round,
-        round_keys)
-    from repro.core.scoring import init_scores
+        init_comp_state, make_allgather_round, make_distributed_round)
     from repro.data import (CIFAR_LIKE, MNIST_LIKE,
-                            make_federated_image_dataset,
-                            sample_client_batches)
+                            make_federated_image_dataset)
     from repro.models import build_model
 
-    N = args.clients
-    if len(jax.devices()) < N:
-        raise SystemExit(f"need {N} devices, have {len(jax.devices())}; "
-                         "set XLA_FLAGS before running")
-    mesh = Mesh(np.asarray(jax.devices()[:N]), ("clients",))
-
-    if args.population is not None:
-        _run_population(args, mesh)
-        return
-
+    N = mesh.shape["clients"]
     arch = ("fedtest-cnn-mnist" if args.dataset == "mnist_like"
             else "fedtest-cnn")
     cfg = get_config(arch).replace(cnn_channels=(8, 16, 16), cnn_hidden=32)
@@ -226,21 +230,34 @@ def main():
                             counts=data.train.counts,
                             server_data=(data.server_x[:256],
                                          data.server_y[:256])))
-
-    params = model.init(jax.random.PRNGKey(args.seed))
-    scores = init_scores(N)
     # compressed exchange (DESIGN.md §12): the round carries the
     # replicated [N, D] error-feedback buffer through the grown
     # round_fn signature; None (and the 8-arg form) when uncompressed
-    comp = init_comp_state(fed, model)
-    tx, ty = data.test.xs[:, :64], data.test.ys[:, :64]
-    run_key = jax.random.PRNGKey(args.seed + 1)
+    return PodRun(model, fed, tc, data, round_fn,
+                  init_comp_state(fed, model))
 
-    history = {"round": [], "acc": [], "local_loss": [],
-               "malicious_weight": [], "participation_rate": [],
-               "dropped_fraction": []}
-    t0 = time.time()
-    for r in range(args.rounds):
+
+def pod_rounds(pod: PodRun, rounds: int, seed: int
+               ) -> Iterator[Tuple[int, Any, dict]]:
+    """Run ``rounds`` pod rounds; yields ``(round, params, metrics)``.
+
+    The global model starts from ``model.init(PRNGKey(seed))`` and round
+    ``r`` runs on the base key ``fold_in(PRNGKey(seed + 1), r)``.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.engine import round_keys
+    from repro.core.scoring import init_scores
+    from repro.data import sample_client_batches
+
+    fed, tc, data = pod.fed, pod.train, pod.data
+    params = pod.model.init(jax.random.PRNGKey(seed))
+    scores = init_scores(fed.num_users)
+    comp = pod.comp
+    tx, ty = data.test.xs[:, :64], data.test.ys[:, :64]
+    run_key = jax.random.PRNGKey(seed + 1)
+    for r in range(rounds):
         # the engine derives the tester set and the participation mask
         # from the round key itself (repro.core.engine.round_keys); the
         # host only samples the training batches from the same bundle
@@ -248,15 +265,44 @@ def main():
         bx, by = sample_client_batches(round_keys(key).batch, data.train,
                                        fed.local_steps, tc.batch_size)
         if comp is not None:
-            params, scores, comp, metrics = round_fn(
+            params, scores, comp, metrics = pod.round_fn(
                 params, scores, comp, bx, by, tx, ty, key,
                 jnp.asarray(r, jnp.int32))
         else:
-            params, scores, metrics = round_fn(
+            params, scores, metrics = pod.round_fn(
                 params, scores, bx, by, tx, ty, key,
                 jnp.asarray(r, jnp.int32))
-        logits, _ = model.forward_train(params,
-                                        {"images": data.global_x[:400]})
+        yield r, params, metrics
+
+
+def main(argv: Optional[Sequence[str]] = None):
+    args = build_parser().parse_args(argv)
+
+    # the device count must be set before jax initialises
+    if "--xla_force_host_platform_device_count" not in \
+            os.environ.get("XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={args.clients}")
+
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    import jax.numpy as jnp
+
+    mesh = client_mesh(args.clients)
+    if args.population is not None:
+        _run_population(args, mesh)
+        return
+
+    pod = build_pod(args, mesh)
+    fed, data = pod.fed, pod.data
+    history = {"round": [], "acc": [], "local_loss": [],
+               "malicious_weight": [], "participation_rate": [],
+               "dropped_fraction": []}
+    t0 = time.time()
+    for r, params, metrics in pod_rounds(pod, args.rounds, args.seed):
+        logits, _ = pod.model.forward_train(
+            params, {"images": data.global_x[:400]})
         acc = float((jnp.argmax(logits, -1) == data.global_y[:400]).mean())
         history["round"].append(r + 1)
         history["acc"].append(acc)
@@ -274,7 +320,8 @@ def main():
               f"drop={float(metrics['dropped_fraction']):.2f} "
               f"({args.exchange} exchange)", flush=True)
     history["wall_s"] = time.time() - t0
-    history["config"] = {"clients": N, "aggregator": fed.aggregator,
+    history["config"] = {"clients": fed.num_users,
+                         "aggregator": fed.aggregator,
                          "attack": fed.attack,
                          "malicious": fed.num_malicious,
                          "attack_scale": fed.attack_scale,
@@ -303,8 +350,8 @@ def main():
               f"{args.assert_malicious_below}")
 
 
-def _run_population(args, mesh):
-    """--population path: cohort engine, [C] axis sharded over the mesh.
+def build_population(args: argparse.Namespace, mesh):
+    """--population path: cohort engine, [C] axis sharded over ``mesh``.
 
     The pod path pins one client per device; the population tier
     instead shards the *cohort* stack across the same ``clients`` mesh
@@ -312,10 +359,10 @@ def _run_population(args, mesh):
     count. Cross-device reductions are not bitwise-stable, so this path
     is gated on adversary suppression (``--assert-malicious-below``),
     not bit-parity — the unsharded parity matrix lives in
-    ``tests/test_population.py``.
+    ``tests/test_population.py``. ``mesh=None`` runs the same cohort
+    unsharded on the default device. Returns ``(fed, data, trainer)``.
     """
     import dataclasses as dc
-    import jax
 
     from repro.config import FedConfig, TrainConfig
     from repro.configs import get_config, scenario_for_population
@@ -325,7 +372,7 @@ def _run_population(args, mesh):
 
     if args.cohort is None:
         raise SystemExit("--population requires --cohort")
-    if args.cohort % args.clients != 0:
+    if mesh is not None and args.cohort % args.clients != 0:
         raise SystemExit(
             f"--cohort {args.cohort} must divide evenly across "
             f"--clients {args.clients} devices for the cohort-axis "
@@ -381,6 +428,13 @@ def _run_population(args, mesh):
     trainer = PopulationTrainer(
         model, fed, tc, mesh=mesh, eval_batch=64,
         testers_from_cohort=args.testers_from_cohort)
+    return fed, data, trainer
+
+
+def _run_population(args, mesh):
+    import jax
+
+    fed, data, trainer = build_population(args, mesh)
     t0 = time.time()
     state, history = trainer.run(jax.random.PRNGKey(args.seed), data,
                                  verbose=True)

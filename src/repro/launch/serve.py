@@ -29,6 +29,7 @@ from repro.core.engine import RoundState
 from repro.core.scoring import init_scores
 from repro.models import build_model
 from repro.models.frontend_stub import stub_embeddings
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def load_serving_params(mgr: CheckpointManager, model, arch: str = None,
@@ -71,6 +72,7 @@ def load_serving_params(mgr: CheckpointManager, model, arch: str = None,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--smoke", action="store_true")

@@ -12,6 +12,7 @@ from repro.optim import make_optimizer
 
 def make_train_step(model, train_cfg: TrainConfig):
     opt = make_optimizer(train_cfg)
+    model = model.for_training()
 
     def train_step(params, opt_state, batch
                    ) -> Tuple[Any, Any, Dict[str, jnp.ndarray]]:

@@ -30,6 +30,7 @@ import json
 import os
 import signal
 import time
+from typing import Any, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +49,7 @@ from repro.data import (
 from repro.data.partition import build_client_arrays
 from repro.data.pipeline import FederatedDataset, split_client_holdout
 from repro.models import build_model
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def make_lm_federated_dataset(vocab: int, num_users: int, seq_len: int = 64,
@@ -99,7 +101,7 @@ _FED_CLI_DEFAULTS = dict(
     compressor="identity", compressor_kwargs={}, seed=0)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="fedtest-cnn")
     ap.add_argument("--smoke", action="store_true",
@@ -213,8 +215,22 @@ def main():
                     help="restore the newest checkpoint from --ckpt-dir "
                          "and continue to --rounds; refuses a manifest "
                          "mismatch")
-    args = ap.parse_args()
+    return ap
 
+
+class Run(NamedTuple):
+    """What :func:`build_run` makes from the parsed flags."""
+
+    cfg: Any            # ModelConfig
+    model: Any          # repro.models.Model
+    fed: FedConfig
+    train: TrainConfig
+    data: Any           # FederatedDataset | DensePopulationData
+    trainer: FederatedTrainer
+
+
+def build_run(args: argparse.Namespace) -> Run:
+    """Model, configs, client data and trainer for the parsed flags."""
     cfg = get_config(args.arch)
     if args.dataset == "mnist_like" and args.arch == "fedtest-cnn":
         cfg = get_config("fedtest-cnn-mnist")
@@ -296,6 +312,13 @@ def main():
         trainer = FederatedTrainer(
             model, fed, tc, rounds_per_call=args.rounds_per_call,
             eval_resample_every=args.eval_resample_every)
+    return Run(cfg, model, fed, tc, data, trainer)
+
+
+def main(argv: Optional[Sequence[str]] = None):
+    enable_compile_cache()
+    args = build_parser().parse_args(argv)
+    cfg, model, fed, tc, data, trainer = build_run(args)
 
     mgr = None
     if args.ckpt_dir:

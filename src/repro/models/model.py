@@ -51,6 +51,18 @@ class Model:
     def dtype(self):
         return _DTYPES[self.cfg.dtype]
 
+    def for_training(self) -> "Model":
+        """This model with ``auto`` kernels resolved for differentiation.
+
+        No Pallas kernel here defines a backward pass, so every training
+        step resolves ``auto`` attention and SSD scans to their XLA
+        implementations, on every platform; explicit choices are kept.
+        """
+        return dataclasses.replace(
+            self,
+            attn_impl="xla" if self.attn_impl == "auto" else self.attn_impl,
+            ssm_impl="xla" if self.ssm_impl == "auto" else self.ssm_impl)
+
     # ------------------------------------------------------------------ init
     def init(self, key) -> Dict[str, Any]:
         cfg = self.cfg

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.dequant_aggregate.kernel import dequant_aggregate_pallas
 from repro.kernels.dequant_aggregate.ops import dequant_aggregate

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.config import FedConfig, TrainConfig, reduce_for_smoke
 from repro.configs import get_config
 from repro.core import FederatedTrainer
