@@ -1,0 +1,25 @@
+"""Published peaks of each accelerator, keyed by JAX's ``device_kind``.
+
+A device that is not in the table is an error, never a default.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "int8_ops_per_s": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "source": "Google Cloud documentation, 'TPU v5e' (system "
+                  "architecture: per-chip peak compute and HBM)",
+    },
+}
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; add "
+                       f"them, with their source, to fedbench/peaks.py"
+                       ) from None
