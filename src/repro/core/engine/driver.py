@@ -32,6 +32,7 @@ from repro.core.engine.backends import LocalBackend
 from repro.core.engine.program import RoundProgram, round_keys
 from repro.core.scoring import ScoreState, init_scores
 from repro.data.pipeline import FederatedDataset, sample_client_batches
+from repro.utils import tracing
 
 
 class RoundState(NamedTuple):
@@ -78,6 +79,7 @@ class FederatedTrainer:
         self.selector = self.program.selector
         self.coalition = self.program.coalition
         self.num_traces = 0
+        self._dispatched = 0     # run_round calls: the step of its span
         self._round_fn = jax.jit(self._round_body)
         # the scanned driver donates the carried RoundState so XLA can
         # reuse the global-model and score buffers across chunks
@@ -160,33 +162,41 @@ class FederatedTrainer:
     def _round_body(self, state: RoundState, data: FederatedDataset):
         self.num_traces += 1        # python side-effect: runs per trace only
         fed = self.fed
-        keys = round_keys(jax.random.fold_in(state.key, state.round_idx))
-        tester_ids, part_mask = self.program.select_round(
-            keys, state.round_idx, scores=state.scores.scores)
-        bx, by = sample_client_batches(keys.batch, data.train,
-                                       fed.local_steps,
-                                       self.train.batch_size)
-        if self.eval_resample_every > 0:
-            # schedule-keyed eval batches: a pure function of the carried
-            # run key and the round bucket, derived in-trace — nothing is
-            # stashed, so resume stays bit-identical (DESIGN.md §10)
-            tx, ty = sampled_eval_batches(
-                state.key, data.test, self.eval_batch, state.round_idx,
-                self.eval_resample_every)
-        else:
-            tx = data.test.xs[:, :self.eval_batch]
-            ty = data.test.ys[:, :self.eval_batch]
+        with jax.named_scope(tracing.SELECT):
+            keys = round_keys(jax.random.fold_in(state.key,
+                                                 state.round_idx))
+            tester_ids, part_mask = self.program.select_round(
+                keys, state.round_idx, scores=state.scores.scores)
+        with jax.named_scope(tracing.TRAIN):
+            bx, by = sample_client_batches(keys.batch, data.train,
+                                           fed.local_steps,
+                                           self.train.batch_size)
+        with jax.named_scope(tracing.CROSS_TEST):
+            if self.eval_resample_every > 0:
+                # schedule-keyed eval batches: a pure function of the
+                # carried run key and the round bucket, derived in-trace —
+                # nothing is stashed, so resume stays bit-identical
+                # (DESIGN.md §10)
+                tx, ty = sampled_eval_batches(
+                    state.key, data.test, self.eval_batch, state.round_idx,
+                    self.eval_resample_every)
+            else:
+                tx = data.test.xs[:, :self.eval_batch]
+                ty = data.test.ys[:, :self.eval_batch]
+        with jax.named_scope(tracing.SCORE):
+            server_data = (data.server_x[:self.eval_batch],
+                           data.server_y[:self.eval_batch])
         new_global, new_scores, new_comp, metrics = self.program.run(
             self.backend, state.global_params, state.scores,
             bx=bx, by=by, tx=tx, ty=ty,
             tester_ids=tester_ids, part_mask=part_mask, keys=keys,
             round_idx=state.round_idx, counts=data.train.counts,
-            server_data=(data.server_x[:self.eval_batch],
-                         data.server_y[:self.eval_batch]),
-            comp_state=state.comp_state)
-        new_state = RoundState(global_params=new_global, scores=new_scores,
-                               round_idx=state.round_idx + 1,
-                               key=state.key, comp_state=new_comp)
+            server_data=server_data, comp_state=state.comp_state)
+        with jax.named_scope(tracing.AGGREGATE):
+            new_state = RoundState(global_params=new_global,
+                                   scores=new_scores,
+                                   round_idx=state.round_idx + 1,
+                                   key=state.key, comp_state=new_comp)
         return new_state, metrics
 
     def _multi_round(self, state: RoundState, data: FederatedDataset):
@@ -201,7 +211,9 @@ class FederatedTrainer:
 
     # ------------------------------------------------------------------- API
     def run_round(self, state: RoundState, data: FederatedDataset):
-        return self._round_fn(state, data)
+        self._dispatched += 1
+        with tracing.span(tracing.ROUND, step=self._dispatched):
+            return self._round_fn(state, data)
 
     def compile_driver(self, state: RoundState, data: FederatedDataset):
         """Compile, ahead of time, the program that ``run`` dispatches
@@ -214,9 +226,10 @@ class FederatedTrainer:
 
     def global_accuracy(self, state: RoundState, data: FederatedDataset,
                         max_samples: int = 2048) -> float:
-        return float(self._global_eval(state.global_params,
-                                       data.global_x[:max_samples],
-                                       data.global_y[:max_samples]))
+        with tracing.span(tracing.GLOBAL_EVAL):
+            return float(self._global_eval(state.global_params,
+                                           data.global_x[:max_samples],
+                                           data.global_y[:max_samples]))
 
     def run(self, key, data: FederatedDataset, rounds: Optional[int] = None,
             eval_every: int = 1, verbose: bool = False,
@@ -254,19 +267,21 @@ class FederatedTrainer:
         while done < rounds:
             if should_stop is not None and should_stop():
                 break
-            if (self._scan_fn is not None
-                    and rounds - done >= self.rounds_per_call):
-                state, chunk = self._scan_fn(state, data)
-                programs_used.add("scan")
-                step = self.rounds_per_call
-                metrics = {k: v[-1] for k, v in chunk.items()}
-            else:
-                state, metrics = self._round_fn(state, data)
-                programs_used.add("single")
-                step = 1
+            with tracing.span(tracing.ROUND, step=done):
+                if (self._scan_fn is not None
+                        and rounds - done >= self.rounds_per_call):
+                    state, chunk = self._scan_fn(state, data)
+                    programs_used.add("scan")
+                    step = self.rounds_per_call
+                    metrics = {k: v[-1] for k, v in chunk.items()}
+                else:
+                    state, metrics = self._round_fn(state, data)
+                    programs_used.add("single")
+                    step = 1
             done += step
             if ckpt is not None:
-                ckpt.maybe_save(done, state)
+                with tracing.span(tracing.CHECKPOINT):
+                    ckpt.maybe_save(done, state)
             if done % eval_every == 0 or done >= rounds or step > 1:
                 ga = self.global_accuracy(state, data)
                 history["round"].append(done)

@@ -59,6 +59,7 @@ from repro.core.engine.backends import ExchangeBackend, _flatten_updates
 from repro.core.engine.driver import FederatedTrainer, RoundState
 from repro.core.engine.program import round_keys
 from repro.kernels.weighted_aggregate import aggregate_pytree
+from repro.utils import tracing
 from repro.utils.pytree import tree_add_vector
 
 
@@ -373,37 +374,47 @@ class PopulationTrainer(FederatedTrainer):
     def _round_body(self, state: RoundState, data):
         self.num_traces += 1
         fed = self.fed
-        keys = round_keys(jax.random.fold_in(state.key, state.round_idx))
-        tester_ids, part_mask = self.program.select_round(
-            keys, state.round_idx, scores=state.scores.scores)
-        idx, valid, eff_mask = cohort_from_mask(part_mask, self.capacity)
-        if self.testers_from_cohort:
-            pop_count = jnp.maximum(jnp.sum(valid).astype(jnp.int32), 1)
-            tester_ids = jnp.minimum(idx[tester_ids % pop_count],
-                                     fed.num_users - 1)
-        safe = jnp.minimum(idx, fed.num_users - 1)
-        # the dense engine's exact batch-index draw
-        # (data.pipeline.sample_client_batches), gathered down to the
-        # cohort rows: the uniform draw stays [N, steps, batch] (cheap —
-        # floats, not images) so keys.batch produces bit-identical
-        # per-client indices, but only O(C) batch *data* is gathered.
-        counts = data.train_counts
-        u = jax.random.uniform(keys.batch,
-                               (fed.num_users, fed.local_steps,
-                                self.train.batch_size))
-        bidx = (u * counts[:, None, None]).astype(jnp.int32)[safe]
-        cx, cy = data.cohort_train(safe)
-        bx = jax.vmap(lambda x, i: x[i])(cx, bidx)
-        by = jax.vmap(lambda y, i: y[i])(cy, bidx)
-        tx, ty = data.tester_batches(tester_ids, self.eval_batch)
+        with jax.named_scope(tracing.SELECT):
+            keys = round_keys(jax.random.fold_in(state.key,
+                                                 state.round_idx))
+            tester_ids, part_mask = self.program.select_round(
+                keys, state.round_idx, scores=state.scores.scores)
+            idx, valid, eff_mask = cohort_from_mask(part_mask,
+                                                    self.capacity)
+            if self.testers_from_cohort:
+                pop_count = jnp.maximum(jnp.sum(valid).astype(jnp.int32),
+                                        1)
+                tester_ids = jnp.minimum(idx[tester_ids % pop_count],
+                                         fed.num_users - 1)
+            safe = jnp.minimum(idx, fed.num_users - 1)
+        with jax.named_scope(tracing.TRAIN):
+            # the dense engine's exact batch-index draw
+            # (data.pipeline.sample_client_batches), gathered down to the
+            # cohort rows: the uniform draw stays [N, steps, batch]
+            # (cheap — floats, not images) so keys.batch produces
+            # bit-identical per-client indices, but only O(C) batch
+            # *data* is gathered.
+            counts = data.train_counts
+            u = jax.random.uniform(keys.batch,
+                                   (fed.num_users, fed.local_steps,
+                                    self.train.batch_size))
+            bidx = (u * counts[:, None, None]).astype(jnp.int32)[safe]
+            cx, cy = data.cohort_train(safe)
+            bx = jax.vmap(lambda x, i: x[i])(cx, bidx)
+            by = jax.vmap(lambda y, i: y[i])(cy, bidx)
+        with jax.named_scope(tracing.CROSS_TEST):
+            tx, ty = data.tester_batches(tester_ids, self.eval_batch)
+        with jax.named_scope(tracing.SCORE):
+            server_data = data.server_batch(self.eval_batch)
         new_global, new_scores, new_comp, metrics = self.program.run(
             self.backend, state.global_params, state.scores,
             bx=(idx, valid, bx), by=by, tx=tx, ty=ty,
             tester_ids=tester_ids, part_mask=eff_mask, keys=keys,
             round_idx=state.round_idx, counts=counts,
-            server_data=data.server_batch(self.eval_batch),
-            comp_state=state.comp_state)
-        new_state = RoundState(global_params=new_global, scores=new_scores,
-                               round_idx=state.round_idx + 1,
-                               key=state.key, comp_state=new_comp)
+            server_data=server_data, comp_state=state.comp_state)
+        with jax.named_scope(tracing.AGGREGATE):
+            new_state = RoundState(global_params=new_global,
+                                   scores=new_scores,
+                                   round_idx=state.round_idx + 1,
+                                   key=state.key, comp_state=new_comp)
         return new_state, metrics

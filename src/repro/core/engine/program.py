@@ -64,6 +64,7 @@ from repro.core.scoring import score_weights
 from repro.optim import make_optimizer
 from repro.strategies.base import (
     Aggregator, AttackContext, RoundContext, uses_combine)
+from repro.utils import tracing
 from repro.utils.pytree import tree_add_vector
 
 
@@ -395,32 +396,37 @@ class RoundProgram:
         # masked tester row), so every downstream path is shared code.
         dropped_fraction = jnp.zeros(())
         if self.use_faults:
-            alive = self.fault.mask(keys.fault, fed.num_users, round_idx)
-            effective = compose_fault_mask(part_mask, alive)
-            dropped_fraction = ((jnp.sum(part_mask) - jnp.sum(effective))
-                                / jnp.maximum(jnp.sum(part_mask), 1.0))
+            with jax.named_scope(tracing.SELECT):
+                alive = self.fault.mask(keys.fault, fed.num_users,
+                                        round_idx)
+                effective = compose_fault_mask(part_mask, alive)
+                dropped_fraction = (
+                    (jnp.sum(part_mask) - jnp.sum(effective))
+                    / jnp.maximum(jnp.sum(part_mask), 1.0))
             pmask = effective
 
         # 1-2. broadcast + local training; losses come back as a
         # replicated [N] vector whatever the backend topology
-        models, local_loss = backend.train(self.local_train, global_params,
-                                           bx, by)
+        with jax.named_scope(tracing.TRAIN):
+            models, local_loss = backend.train(self.local_train,
+                                               global_params, bx, by)
 
         # 3. adversaries act (strategy; malicious set can live anywhere).
         # The AttackContext exposes the cross-testing signal *entering*
         # the round — the scores and the aggregation weights they imply —
         # so adaptive attacks can react to being suppressed.
-        actx = AttackContext(scores=scores.scores,
-                             weights=score_weights(scores),
-                             round_idx=round_idx)
-        models = backend.apply_attack(self.attack, keys.attack, models,
-                                      global_params, actx)
+        with jax.named_scope(tracing.ATTACK):
+            actx = AttackContext(scores=scores.scores,
+                                 weights=score_weights(scores),
+                                 round_idx=round_idx)
+            models = backend.apply_attack(self.attack, keys.attack, models,
+                                          global_params, actx)
 
-        # 3b. non-participants transmit nothing this round: whoever
-        # evaluates their slot sees the stale global copy — attacked or
-        # not, an unsampled client's model never leaves the device.
-        if pmask is not None:
-            models = backend.mask_models(models, global_params, pmask)
+            # 3b. non-participants transmit nothing this round: whoever
+            # evaluates their slot sees the stale global copy — attacked
+            # or not, an unsampled client's model never leaves the device.
+            if pmask is not None:
+                models = backend.mask_models(models, global_params, pmask)
 
         # 3c. compressed exchange (DESIGN.md §12): each participating
         # client encodes its flat update (with error feedback banked in
@@ -433,108 +439,112 @@ class RoundProgram:
         new_comp_state = comp_state
         comp_payloads = comp_decoded = None
         if self.use_compression:
-            models, comp_payloads, comp_decoded, new_comp_state = (
-                backend.compress_exchange(self.compressor, models,
-                                          global_params, comp_state,
-                                          pmask))
+            with jax.named_scope(tracing.EXCHANGE):
+                models, comp_payloads, comp_decoded, new_comp_state = (
+                    backend.compress_exchange(self.compressor, models,
+                                              global_params, comp_state,
+                                              pmask))
 
         # 4. the round's testers measure accuracies on their own data.
         # The backend returns the replicated [K, N] matrix A[k, c] (and
         # an opaque cache, e.g. the all-gathered models, that
         # ``backend.updates`` may reuse so nothing is exchanged twice).
-        acc, cache = backend.cross_test(self.eval_fn, models, tx, ty,
-                                        tester_ids)
+        with jax.named_scope(tracing.CROSS_TEST):
+            acc, cache = backend.cross_test(self.eval_fn, models, tx, ty,
+                                            tester_ids)
 
-        # 5. lying testers (Sec. V-C): users with id < lying_testers
-        # report uniform random accuracies whenever selected to test.
-        # The matrix is replicated, so this works on every backend.
-        if fed.lying_testers:
-            lies = jax.random.uniform(keys.lie, acc.shape)
-            liar_rows = (tester_ids < fed.lying_testers)[:, None]
-            acc = jnp.where(liar_rows, lies, acc)
+        with jax.named_scope(tracing.SCORE):
+            # 5. lying testers (Sec. V-C): users with id < lying_testers
+            # report uniform random accuracies whenever selected to test.
+            # The matrix is replicated, so this works on every backend.
+            if fed.lying_testers:
+                lies = jax.random.uniform(keys.lie, acc.shape)
+                liar_rows = (tester_ids < fed.lying_testers)[:, None]
+                acc = jnp.where(liar_rows, lies, acc)
 
-        # 5b. coalition report-space attack (DESIGN.md §7): members
-        # selected as testers rewrite their rows of the replicated
-        # matrix (mutual boost + targeted defamation driven by the
-        # AttackContext scores). Replicated matrix -> shared code ->
-        # bit-identical on every backend.
-        if self.coalition_active:
-            acc = self.coalition.transform_reports(
-                jax.random.fold_in(keys.lie, 1), acc, tester_ids, actx)
+            # 5b. coalition report-space attack (DESIGN.md §7): members
+            # selected as testers rewrite their rows of the replicated
+            # matrix (mutual boost + targeted defamation driven by the
+            # AttackContext scores). Replicated matrix -> shared code ->
+            # bit-identical on every backend.
+            if self.coalition_active:
+                acc = self.coalition.transform_reports(
+                    jax.random.fold_in(keys.lie, 1), acc, tester_ids, actx)
 
-        # 6. weights via the aggregation strategy
-        server_eval = None
-        if self.aggregator.needs_server_eval:
-            if server_data is None:
-                raise ValueError(
-                    f"aggregator {self.aggregator.name!r} needs a "
-                    "server-side eval set; pass server_data=(sx, sy)")
-            sx, sy = server_data
-            server_eval = backend.server_eval(self.eval_fn, models, sx, sy)
-        # the [N, D] update matrix is materialised at most once per round
-        # and shared between ctx.updates consumers and the combine path
-        updates = (backend.updates(models, global_params, cache)
-                   if self.needs_updates else None)
-        ctx = RoundContext(acc_matrix=acc, tester_ids=tester_ids,
-                           scores=scores, counts=counts,
-                           round_idx=round_idx, key=keys.agg,
-                           updates=updates, server_eval=server_eval,
-                           participation=pmask,
-                           report_mask=(pmask[tester_ids]
-                                        if pmask is not None else None))
-        # non-sampled clients' scores freeze inside update_scores
-        # (client_mask=ctx.participation): no evidence about an absent
-        # client — a suppressed attacker stays suppressed while it sits
-        # out. One code path for every backend.
-        new_scores = self.aggregator.update_scores(ctx)
-        ctx = ctx._replace(scores=new_scores)
-        weights = self.aggregator.weights(ctx)
-        if pmask is not None:
-            weights = renormalize_over_subset(weights, pmask)
+            # 6. weights via the aggregation strategy
+            server_eval = None
+            if self.aggregator.needs_server_eval:
+                if server_data is None:
+                    raise ValueError(
+                        f"aggregator {self.aggregator.name!r} needs a "
+                        "server-side eval set; pass server_data=(sx, sy)")
+                sx, sy = server_data
+                server_eval = backend.server_eval(self.eval_fn, models, sx, sy)
+            # the [N, D] update matrix is materialised at most once per round
+            # and shared between ctx.updates consumers and the combine path
+            updates = (backend.updates(models, global_params, cache)
+                       if self.needs_updates else None)
+            ctx = RoundContext(acc_matrix=acc, tester_ids=tester_ids,
+                               scores=scores, counts=counts,
+                               round_idx=round_idx, key=keys.agg,
+                               updates=updates, server_eval=server_eval,
+                               participation=pmask,
+                               report_mask=(pmask[tester_ids]
+                                            if pmask is not None else None))
+            # non-sampled clients' scores freeze inside update_scores
+            # (client_mask=ctx.participation): no evidence about an absent
+            # client — a suppressed attacker stays suppressed while it sits
+            # out. One code path for every backend.
+            new_scores = self.aggregator.update_scores(ctx)
+            ctx = ctx._replace(scores=new_scores)
+            weights = self.aggregator.weights(ctx)
+            if pmask is not None:
+                weights = renormalize_over_subset(weights, pmask)
 
-        # 7. aggregation -> new global model: the per-coordinate combine
-        # fast path runs replicated on the [N, D] matrix (identical on
-        # every backend); the weights path reduces through the backend
-        # (fused weighted sum locally, one psum on the pod).
-        if self.uses_combine:
-            new_global = tree_add_vector(
-                global_params, self.aggregator.combine(ctx, updates))
-        elif self.use_compression:
-            # compressed weights path: aggregate in *update space* from
-            # the wire representation (the fused dequant_aggregate
-            # kernel for int8 — the f32 [C, D] stack never hits HBM),
-            # then one tree_add_vector back into model space. Same
-            # formula on every backend (local kernel == pod psum, the
-            # §3 replication contract).
-            new_global = tree_add_vector(
-                global_params,
-                backend.compressed_sum(self.compressor, comp_payloads,
-                                       comp_decoded, weights, models,
-                                       self.agg_impl))
-        else:
-            new_global = backend.weighted_sum(models, weights,
-                                              global_params, self.agg_impl)
+        with jax.named_scope(tracing.AGGREGATE):
+            # 7. aggregation -> new global model: the per-coordinate combine
+            # fast path runs replicated on the [N, D] matrix (identical on
+            # every backend); the weights path reduces through the backend
+            # (fused weighted sum locally, one psum on the pod).
+            if self.uses_combine:
+                new_global = tree_add_vector(
+                    global_params, self.aggregator.combine(ctx, updates))
+            elif self.use_compression:
+                # compressed weights path: aggregate in *update space* from
+                # the wire representation (the fused dequant_aggregate
+                # kernel for int8 — the f32 [C, D] stack never hits HBM),
+                # then one tree_add_vector back into model space. Same
+                # formula on every backend (local kernel == pod psum, the
+                # §3 replication contract).
+                new_global = tree_add_vector(
+                    global_params,
+                    backend.compressed_sum(self.compressor, comp_payloads,
+                                           comp_decoded, weights, models,
+                                           self.agg_impl))
+            else:
+                new_global = backend.weighted_sum(models, weights,
+                                                  global_params, self.agg_impl)
 
-        # the malicious index set comes from the attack strategy, so the
-        # metric stays correct for any placement of the attackers.
-        mal_w = (jnp.sum(weights * self.malicious_mask)
-                 if self.malicious_idx else jnp.zeros(()))
-        # losses of non-participants are discarded work (their training
-        # never left the device) — the mean runs over the sampled subset
-        metrics = {
-            "local_loss": (jnp.sum(local_loss * pmask)
-                           / jnp.maximum(jnp.sum(pmask), 1)
-                           if pmask is not None
-                           else jnp.mean(local_loss)),
-            "acc_matrix_mean": jnp.mean(acc),
-            "weights": weights,
-            "malicious_weight": mal_w,
-            "scores": new_scores.scores,
-            "participation_rate": (jnp.mean(pmask)
-                                   if pmask is not None
-                                   else jnp.ones(())),
-            # fraction of *selected* clients lost to faults this round
-            # (0 under fault='none'; DESIGN.md §9)
-            "dropped_fraction": dropped_fraction,
-        }
+            # the malicious index set comes from the attack strategy, so the
+            # metric stays correct for any placement of the attackers.
+            mal_w = (jnp.sum(weights * self.malicious_mask)
+                     if self.malicious_idx else jnp.zeros(()))
+            # losses of non-participants are discarded work (their training
+            # never left the device) — the mean runs over the sampled subset
+            metrics = {
+                "local_loss": (jnp.sum(local_loss * pmask)
+                               / jnp.maximum(jnp.sum(pmask), 1)
+                               if pmask is not None
+                               else jnp.mean(local_loss)),
+                "acc_matrix_mean": jnp.mean(acc),
+                "weights": weights,
+                "malicious_weight": mal_w,
+                "scores": new_scores.scores,
+                "participation_rate": (jnp.mean(pmask)
+                                       if pmask is not None
+                                       else jnp.ones(())),
+                # fraction of *selected* clients lost to faults this round
+                # (0 under fault='none'; DESIGN.md §9)
+                "dropped_fraction": dropped_fraction,
+            }
         return new_global, new_scores, new_comp_state, metrics
