@@ -146,6 +146,21 @@ def renormalize_over_subset(weights: jnp.ndarray, part_mask: jnp.ndarray
                      part_mask / jnp.sum(part_mask))
 
 
+def pairwise_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum of a vector by a pairwise tree that this code fixes.
+
+    ``jnp.sum`` leaves the order of the additions to XLA, which picks it
+    per fusion: the dense and the cohort engine then add the same
+    per-client values in different orders and differ in the last bit.
+    """
+    while x.shape[0] > 1:
+        if x.shape[0] % 2:
+            x = jnp.concatenate([x, jnp.zeros((1,), x.dtype)])
+        half = x.shape[0] // 2
+        x = x[:half] + x[half:]
+    return x[0]
+
+
 def aggregator_defaults(fed: FedConfig, use_trust: bool = False
                         ) -> Dict[str, Any]:
     """Engine-derived default kwargs offered to aggregator constructors.
@@ -532,7 +547,7 @@ class RoundProgram:
             # losses of non-participants are discarded work (their training
             # never left the device) — the mean runs over the sampled subset
             metrics = {
-                "local_loss": (jnp.sum(local_loss * pmask)
+                "local_loss": (pairwise_sum(local_loss * pmask)
                                / jnp.maximum(jnp.sum(pmask), 1)
                                if pmask is not None
                                else jnp.mean(local_loss)),

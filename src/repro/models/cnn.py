@@ -41,14 +41,14 @@ def init_cnn(cfg, key, dtype=jnp.float32) -> Dict:
 
 
 def _maxpool2(x):
-    B, H, W, C = x.shape
-    ph, pw = (-H) % 2, (-W) % 2
-    if ph or pw:
-        x = jnp.pad(x, ((0, 0), (0, ph), (0, pw), (0, 0)),
-                    constant_values=-jnp.inf)
-    H2, W2 = x.shape[1] // 2, x.shape[2] // 2
-    x = x.reshape(B, H2, 2, W2, 2, C)
-    return x.max(axis=(2, 4))
+    """2x2 stride-2 max-pool of NHWC ``x``, padding an odd H or W with
+    -inf. One reduce-window keeps the layout the convolution writes, so
+    XLA fuses bias, ReLU and the pool into one pass; its gradient is a
+    select-and-scatter, with no count of ties and no divide."""
+    _, H, W, _ = x.shape
+    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
+                                 (1, 2, 2, 1),
+                                 ((0, 0), (0, H % 2), (0, W % 2), (0, 0)))
 
 
 def cnn_forward(p, cfg, images: jnp.ndarray) -> jnp.ndarray:

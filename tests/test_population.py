@@ -20,6 +20,7 @@ from repro.config import FedConfig, TrainConfig
 from repro.configs import get_config, scenario_for_population
 from repro.core.engine import (FederatedTrainer, PopulationTrainer,
                                cohort_from_mask)
+from repro.core.engine.program import pairwise_sum
 from repro.data import MNIST_LIKE, make_federated_image_dataset
 from repro.data.population import DensePopulationData
 from repro.models import build_model
@@ -86,6 +87,23 @@ def test_cohort_matches_dense_bitwise(setup, case, participation):
             assert _tree_equal(a, b), (
                 f"{case} participation={participation} round {r}: "
                 f"{name} diverged from the dense engine")
+
+
+@pytest.mark.parametrize("n", [1, 5, 8, 20])
+def test_pairwise_sum_adds_in_a_fixed_tree(n):
+    """The sampled-subset loss is summed in an order the code fixes, so
+    both engines add their per-client losses alike: halves added
+    elementwise, a zero appended to an odd length."""
+    x = jax.random.normal(jax.random.PRNGKey(n), (n,))
+    want = np.asarray(x)
+    while len(want) > 1:
+        if len(want) % 2:
+            want = np.append(want, np.float32(0))
+        want = want[:len(want) // 2] + want[len(want) // 2:]
+    got = jax.jit(pairwise_sum)(x)
+    assert np.asarray(got).view(np.int32) == want[0].view(np.int32)
+    ints = jnp.arange(n, dtype=jnp.float32)
+    assert float(pairwise_sum(ints)) == n * (n - 1) / 2
 
 
 def test_tiled_crosstest_bitwise_matches_untiled(setup):
